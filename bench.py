@@ -452,20 +452,9 @@ def worker() -> int:
                                    + (" (rlc fell back)" if fell_back else "")}))
         return 1
 
-    # Opt-in jax.profiler capture around the timed reps (device-side
-    # attribution for the ROOFLINE budget; the trace perturbs timing,
-    # so the artifact notes it).
-    trace_dir = flags.get_raw("FD_FLIGHT_JAX_TRACE")
     t0 = time.perf_counter()
-    if trace_dir:
-        import jax.profiler as _prof
-
-        with _prof.trace(trace_dir):
-            outs = [fn(*args) for _ in range(reps)]
-            finals = [np.asarray(o) for o in outs]
-    else:
-        outs = [fn(*args) for _ in range(reps)]
-        finals = [np.asarray(o) for o in outs]
+    outs = [fn(*args) for _ in range(reps)]
+    finals = [np.asarray(o) for o in outs]
     dt = time.perf_counter() - t0
     bad = any(not bool((f == 0).all()) for f in finals)
     # COUNT fallbacks, don't just flag them: the artifact must record
@@ -502,7 +491,6 @@ def worker() -> int:
         "compile_s": round(compile_s, 1),
         "engine_key": entry.key,
         "compile_cache_hit_est": entry.cache_hit_est,
-        "jax_trace_dir": trace_dir or None,
         "ms_per_batch": round(1e3 * dt / reps, 2),
         "rlc_fallbacks": fallback_cnt,
     }

@@ -43,8 +43,8 @@ class Slot:
     __slots__ = (
         "idx", "state", "msgs", "lens", "sigs", "pubs", "pay", "offs",
         "plens", "psigs", "tlanes", "tsorigs", "tspubs", "hashes",
-        "ha_mask", "n_txn", "n_lane", "pay_fill", "t_first", "drain_end",
-        "flush_verdict", "rung", "rung_depth",
+        "ha_mask", "n_txn", "n_lane", "pay_fill", "t_first", "t_commit",
+        "drain_end", "flush_verdict", "rung", "rung_depth",
     )
 
     def __init__(self, idx: int, batch: int, max_msg_len: int):
@@ -73,6 +73,7 @@ class Slot:
         self.n_lane = 0
         self.pay_fill = 0
         self.t_first = 0       # deadline anchor (tickcount ns)
+        self.t_commit = 0      # FILLING -> READY (tickcount ns)
         self.drain_end = 0     # in-ring seq after the last drain round
                                # (the batch's ack target once verified)
         # Why this slot shipped ("full" / "capacity" / "deadline" /
@@ -93,6 +94,7 @@ class Slot:
         self.n_lane = 0
         self.pay_fill = 0
         self.t_first = 0
+        self.t_commit = 0
         self.drain_end = 0
         self.flush_verdict = "full"
         self.rung = 0

@@ -34,7 +34,12 @@ until it had destroyed throughput). fd_flight replaces that with:
              ALWAYS-ON log2 histogram — full-population latency per
              edge, replacing the sampling-only p50/p99 as the
              docs/LATENCY.md budget surface (the reservoirs remain for
-             fine-grained percentiles).
+             fine-grained percentiles). Host spans on the profiler's
+             clock (span_maker: fd.feed.drain, fd.verify.dispatch,
+             fd.rlc.*, fd.verify.readback/publish) name the verify
+             batch's host work in a jax.profiler trace, and the
+             verify lane's lifecycle counters (retired, *_ns) split
+             each retired batch into its phases, always on.
 
   RECORDER   a per-tile ring buffer of the last N structured events
              (dispatches, adaptive-flush verdicts, breaker
@@ -45,13 +50,16 @@ until it had destroyed throughput). fd_flight replaces that with:
              failure requires.
 
 Deliberately stdlib+numpy only: host-side tiles must stay
-jax-import-free (disco/tiles.py's dispatch contract).
+jax-import-free (disco/tiles.py's dispatch contract); span_maker
+reaches for jax.profiler only where JAX is already loaded.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -121,8 +129,10 @@ TILE_METRICS: Tuple[Metric, ...] = (
     Metric("slot_stall", "counter",
            "stager slot acquires that had to wait for a FREE slot"),
     Metric("feed_idle_ns", "counter",
-           "dispatcher device-idle estimate (nothing in flight AND "
-           "nothing READY), ns"),
+           "dispatcher host estimate of device idle, ns: counts only "
+           "stretches with nothing in flight AND nothing READY, so it "
+           "misses the device waiting on a per-lane launch (the device "
+           "trace is the authority)"),
     Metric("compile_cnt", "counter",
            "verify-engine (pre)compiles paid by this tile"),
     Metric("compile_ns", "counter",
@@ -209,6 +219,31 @@ TILE_METRICS: Tuple[Metric, ...] = (
            "live reconfig requests refused at validation (invalid "
            "mode/backend combo, unusable ladder, or a swap already "
            "pending)"),
+    # Verify-batch lifecycle (fd_feed): every phase of a retired batch,
+    # booked in one place when the batch retires, after its publish, so
+    # any window's deltas share the one denominator `retired`. The
+    # per-batch mean of a phase is d(<phase>_ns) / d(retired).
+    Metric("retired", "counter",
+           "fd_feed verify batches retired (published and released)"),
+    Metric("ring_wait_ns", "counter",
+           "ring dwell of each retired batch's oldest frag: source "
+           "publish -> the stager's drain of it, ns"),
+    Metric("stage_ns", "counter",
+           "first staged txn -> slot commit (READY), summed over retired "
+           "batches, ns"),
+    Metric("ready_wait_ns", "counter",
+           "slot commit -> dispatch returned (READY queue wait plus the "
+           "host's put and launch), summed over retired batches, ns"),
+    Metric("inflight_ns", "counter",
+           "dispatch -> the completion found the batch ready (the "
+           "device programs, the per-lane launch, the wait for an older "
+           "batch to retire first), summed over retired batches, ns"),
+    Metric("fallback_wait_ns", "counter",
+           "dispatch -> per-lane fallback launch, summed over retired "
+           "batches whose rlc batch equation failed, ns"),
+    Metric("retire_ns", "counter",
+           "found ready -> retired (readback, verdict fold, publish), "
+           "summed over retired batches, ns"),
 )
 
 TILE_IDX: Dict[str, int] = {m.name: i for i, m in enumerate(TILE_METRICS)}
@@ -780,6 +815,29 @@ def enabled() -> bool:
     Read per construction site, never per frag — the hot paths gate on
     the None-ness of the handles this decides."""
     return flags.get_bool("FD_FLIGHT")
+
+
+_NO_SPAN = contextlib.nullcontext()   # stateless: one shared instance
+
+
+def _no_span(name: str) -> contextlib.nullcontext:
+    return _NO_SPAN
+
+
+def span_maker():
+    """The host-span maker for one construction site: ``maker(name)``
+    is a fresh ``jax.profiler.TraceAnnotation(name)`` (a TraceMe event
+    on the profiler's timeline, in the same trace as the device ops)
+    when JAX is already loaded and FD_FLIGHT is on, else the shared
+    no-op context. Resolved once per site, like enabled(); a span
+    object records only once, so the maker builds one per use. This
+    never imports JAX into a process that has not: host-backend tiles
+    stay JAX-free. No span may sit inside a jitted function."""
+    if enabled() and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        return TraceAnnotation
+    return _no_span
 
 
 class FlightRecorder:

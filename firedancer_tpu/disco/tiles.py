@@ -770,6 +770,33 @@ class _InflightBatch:
     drain: object = None
 
 
+def book_retire(fl, ib: _InflightBatch, t_ready: int,
+                t_retired: int) -> None:
+    """Book one retired fd_feed batch's lifecycle into the verify
+    tile's flight lane, from tickcount stamps taken in order: t_first
+    (first staged txn), t_commit (READY), t_dispatch (dispatch
+    returned), t_ready (the completion found it ready), t_retired
+    (after its publish). stage + ready_wait + inflight + retire is
+    exactly t_retired - t_first. The ring wait is the oldest frag's
+    dwell by the drain's wrap-safe rule (0 where it rejects one);
+    fallback_wait only for batches whose per-lane pass ran."""
+    slot = ib.slot
+    fl.inc("retired")
+    ring = xray.dwell32(slot.t_first, int(slot.tspubs[0]))
+    if ring > 0:
+        fl.inc("ring_wait_ns", ring)
+    # A slot handed to _feed_dispatch without a commit (a caller that
+    # stages and dispatches itself, as tests do) waited for none.
+    t_commit = slot.t_commit or ib.t_dispatch
+    fl.inc("stage_ns", t_commit - slot.t_first)
+    fl.inc("ready_wait_ns", ib.t_dispatch - t_commit)
+    fl.inc("inflight_ns", t_ready - ib.t_dispatch)
+    t_fallback = getattr(ib.out, "t_fallback", 0)
+    if t_fallback:
+        fl.inc("fallback_wait_ns", t_fallback - ib.t_dispatch)
+    fl.inc("retire_ns", t_retired - t_ready)
+
+
 class _ReadyBatch:
     """Completed-synchronously result with the async-batch surface
     (_complete polls .is_ready() and np.asarray's the result)."""
@@ -1049,6 +1076,10 @@ class VerifyTile(Tile):
                 if verify_mode == "rlc":
                     self._account_compile(entry.key + ":fallback",
                                           entry.fallback_compile_s)
+        # fd.* host spans on the profiler's clock (no-ops without JAX in
+        # the process or with FD_FLIGHT=0): resolved here, once JAX is
+        # loaded on the device path.
+        self._span = flight.span_maker()
         # fd_engine rung scheduler (feed mode): pick the dispatch B from
         # the FD_ENGINE_LADDER rungs by queue depth + deadline slack
         # (disco/engine.py). Needs >= 2 usable rungs at or below the
@@ -1744,6 +1775,14 @@ class VerifyTile(Tile):
                 # fast-path cannot race a dispatch.
                 self._acked_seq = il.seq
             return 0
+        with self._span("fd.feed.drain"):
+            return self._stage_round(slot, k0, n, seq.value, c)
+
+    def _stage_round(self, slot, k0: int, n: int, seq_end: int, c) -> int:
+        """The bookkeeping of a drain round that staged n txns into
+        `slot` from txn index k0: stamps, ring dwell, HA dedup, cursors.
+        Returns n."""
+        il = self.in_link
         now = tempo.tickcount()
         if k0 == 0:
             slot.t_first = now  # deadline anchor: first STAGED txn
@@ -1796,13 +1835,13 @@ class VerifyTile(Tile):
         slot.pay_fill = int(slot.offs[last]) + int(slot.plens[last])
         slot.n_lane += int(slot.tlanes[k0 : k0 + n].sum())
         slot.n_txn += n
-        slot.drain_end = seq.value
+        slot.drain_end = seq_end
         # Consumed-seq marker only AFTER the txns are visible in the
         # slot (n_txn above): the quiescence check and the ack fast
         # path read both from other threads, and seq-first would open a
         # consumed-but-invisible window where the pipeline looks
         # drained while staged txns exist.
-        il.seq = seq.value
+        il.seq = seq_end
         return n
 
     def _stager_loop(self) -> None:
@@ -1914,6 +1953,7 @@ class VerifyTile(Tile):
 
     def _feed_commit(self, slot, verdict: str = FLUSH_FULL) -> None:
         slot.flush_verdict = verdict  # fd_xray batch-context exemplars
+        slot.t_commit = tempo.tickcount()
         self._feed_slot = None
         self.feed_pool.commit(slot)
 
@@ -1959,27 +1999,28 @@ class VerifyTile(Tile):
             try:
                 if c is not None:
                     c.verify_dispatch_hook()  # may raise ChaosDeviceLost
-                if self.backend == "cpu":
-                    from firedancer_tpu.ballet.ed25519 import (
-                        native as ed_native,
-                    )
+                with self._span("fd.verify.dispatch"):
+                    if self.backend == "cpu":
+                        from firedancer_tpu.ballet.ed25519 import (
+                            native as ed_native,
+                        )
 
-                    out = _FutureBatch(self._feed_exec.submit(
-                        ed_native.verify_arrays,
-                        slot.msgs, slot.lens, slot.sigs, slot.pubs,
-                        slot.n_lane,
-                    ))
-                else:
-                    put = entry.put
-                    msgs = put(slot.msgs[:rung])
-                    if entry.in_sharding is not None:
-                        self._note_shard_rows(msgs)
-                    out = fn(
-                        msgs,
-                        put(slot.lens[:rung].astype(np.int32)),
-                        put(slot.sigs[:rung]),
-                        put(slot.pubs[:rung]),
-                    )
+                        out = _FutureBatch(self._feed_exec.submit(
+                            ed_native.verify_arrays,
+                            slot.msgs, slot.lens, slot.sigs, slot.pubs,
+                            slot.n_lane,
+                        ))
+                    else:
+                        put = entry.put
+                        msgs = put(slot.msgs[:rung])
+                        if entry.in_sharding is not None:
+                            self._note_shard_rows(msgs)
+                        out = fn(
+                            msgs,
+                            put(slot.lens[:rung].astype(np.int32)),
+                            put(slot.sigs[:rung]),
+                            put(slot.pubs[:rung]),
+                        )
                 via_device = True
             except Exception as e:
                 # Device unavailable at dispatch (or the executor
@@ -2918,12 +2959,15 @@ class VerifyTile(Tile):
             ib = self._inflight[0]
             if not block and not ib.out.is_ready():
                 return
+            # Found ready (a blocking retire books its wait as retire).
+            t_ready = tempo.tickcount()
             c = chaos.active()
             quarantined = False
             try:
                 if c is not None:
                     c.verify_complete_hook()  # may raise ChaosBackendError
-                statuses = np.asarray(ib.out)  # blocks only if not ready
+                with self._span("fd.verify.readback"):
+                    statuses = np.asarray(ib.out)  # blocks only if not ready
             except Exception as e:
                 # Poisoned batch: the verify raised instead of returning
                 # statuses. Quarantine — re-verify the whole batch on
@@ -2970,9 +3014,11 @@ class VerifyTile(Tile):
                 # quarantined batch publishes claim-free: its drain aux
                 # dispatch shares the poisoned queue, so its claims are
                 # untrusted too (all maybe-dup = exact downstream).
-                batch_ack = self._publish_feed_batch(
-                    ib.slot, statuses, quarantined=quarantined,
-                    drain=None if quarantined else ib.drain)
+                with self._span("fd.verify.publish"):
+                    batch_ack = self._publish_feed_batch(
+                        ib.slot, statuses, quarantined=quarantined,
+                        drain=None if quarantined else ib.drain)
+                book_retire(self.fl, ib, t_ready, tempo.tickcount())
             else:
                 off = 0
                 batch_ack = 0

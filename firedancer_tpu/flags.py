@@ -761,12 +761,6 @@ _register(
     "can flip this on and signal a live process.",
 )
 _register(
-    "FD_FLIGHT_JAX_TRACE", str, None,
-    "Directory for a jax.profiler trace captured around the bench "
-    "worker's timed reps (device rungs only; the trace is large and "
-    "perturbs timing, so it is opt-in and the artifact notes it).",
-)
-_register(
     "FD_TRACE_SPANS", bool, True,
     "Per-frag trace spans: every OutLink publish (and the fd_feed bulk "
     "completion) observes tspub - tsorig into the edge's always-on "

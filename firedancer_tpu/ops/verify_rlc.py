@@ -71,6 +71,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from firedancer_tpu import flags
+from firedancer_tpu.disco import flight
+from firedancer_tpu.tango import tempo
 
 from . import curve25519 as ge
 from . import fe25519 as fe
@@ -481,16 +483,20 @@ class RlcAsyncResult:
     shim's in-flight accounting and ordering are untouched.
     """
 
-    def __init__(self, rlc_out, fallback_fn, args):
+    def __init__(self, rlc_out, fallback_fn, args, span):
         self._status, self._definite, self._ok = rlc_out
         self._fallback_fn = fallback_fn
         self._args = args
+        self._span = span       # the verifier's flight.span_maker()
         self._fb = None
         self._resolved = None
         self.used_fallback = False
+        self.t_fallback = 0     # tickcount of the per-lane launch
 
     def _start_fallback(self):
-        self._fb = self._fallback_fn(*self._args)
+        self.t_fallback = tempo.tickcount()
+        with self._span("fd.rlc.fallback_launch"):
+            self._fb = self._fallback_fn(*self._args)
         self._args = None
         self.used_fallback = True
 
@@ -539,12 +545,17 @@ def make_async_verifier(fallback_fn, rng: np.random.Generator | None = None,
     if torsion_k is None:
         torsion_k = flags.get_int("FD_RLC_TORSION_K")
 
+    span = flight.span_maker()
+
     def fn(msgs, lens, sigs, pubs):
         bsz = msgs.shape[0]
-        z = jnp.asarray(fresh_z(bsz, rng))
-        u = jnp.asarray(fresh_u(torsion_k, 2 * bsz, rng))
-        out = rlc(msgs, lens, sigs, pubs, z, u)
-        return RlcAsyncResult(out, fallback_fn, (msgs, lens, sigs, pubs))
+        with span("fd.rlc.weights"):
+            z = jnp.asarray(fresh_z(bsz, rng))
+            u = jnp.asarray(fresh_u(torsion_k, 2 * bsz, rng))
+        with span("fd.rlc.launch"):
+            out = rlc(msgs, lens, sigs, pubs, z, u)
+        return RlcAsyncResult(out, fallback_fn, (msgs, lens, sigs, pubs),
+                              span)
 
     return fn
 

@@ -193,19 +193,28 @@ def test_abuse_breaker_quarantines_junk_peer(tmp_path, monkeypatch):
 
         atk = UdpSock()
         atk_tx = atk.aio_tx()
+        # The honest peer starts only once the junk has passed the
+        # threshold with room to spare: the tile reads its socket in
+        # arrival order, so the trip and some drops come before the
+        # honest txns, and a fast honest run cannot end the pipeline
+        # before any quarantined datagram is dropped.
+        tripped = threading.Event()
 
         def attack():
             import random
 
             rng = random.Random(3)
-            for _ in range(200):
+            for i in range(200):
                 atk_tx.send_one(addr, bytes(
                     rng.randrange(256) for _ in range(40)))
+                if i == 20:
+                    tripped.set()
                 time.sleep(0.001)
             atk.close()
 
         t = threading.Thread(target=attack, daemon=True)
         t.start()
+        tripped.wait(timeout=10.0)
         _client(addr, corpus.payloads, n_conns=1)
         t.join(timeout=10.0)
 
