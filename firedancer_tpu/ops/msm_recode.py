@@ -1,7 +1,7 @@
 """fd_msm2 certified core: signed-window recoding + the lazy niels madd.
 
 Two pieces of the signed-digit Pippenger engine live here, OUTSIDE the
-(uncertifiable) gather/argsort staging of ops/msm.py, precisely so the
+(uncertifiable) sort/gather staging of ops/msm.py, precisely so the
 fdcert abstract interpreter can prove them (lint/bounds.py pass 5 —
 this module is a CERT_MODULE):
 

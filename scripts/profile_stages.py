@@ -476,7 +476,7 @@ def main():
         (scal253, pt),
     )
     print(f"msm_fast [37w]:      {t*1e3:8.3f} ms")
-    # staging alone (sort + gather share): how much of msm_fast is XLA.
+    # staging alone (sort + slot table): how much of msm_fast is XLA.
     t = bench_fn(
         jax.jit(lambda s: msm_mod._staging_indices(
             s, msm_mod.WINDOWS_253, batch, 140)[0]),
