@@ -325,6 +325,10 @@ class EngineEntry:
         # Mesh engines: the batch-axis NamedSharding that put() places
         # host inputs with, so each chip receives only its own rows.
         "in_sharding",
+        # Host counters: the rlc weights' draw and placement (booked by
+        # the async verifier per batch), and on a mesh engine the lanes
+        # with a message that put() placed on each shard.
+        "rlc_weights_ns", "rlc_weights_calls", "shard_lanes",
     )
 
     def __init__(self, spec: EngineSpec):
@@ -342,6 +346,9 @@ class EngineEntry:
         self.fn_drain: Optional[Callable] = None
         self.kernels: Dict[str, str] = {}
         self.in_sharding = None
+        self.rlc_weights_ns = 0
+        self.rlc_weights_calls = 0
+        self.shard_lanes: List[int] = [0] * spec.shards
         self.service_local_ns = 0   # EMA: dispatch -> local_fill ready
         self.service_tail_ns = 0    # EMA: local ready -> combine ready
         self.compile_s = 0.0
@@ -379,10 +386,24 @@ class EngineEntry:
         second trace and compile mid-run)."""
         import jax
         import jax.numpy as jnp
+        import numpy as np
 
         if self.in_sharding is None:
             return jnp.asarray(a)
+        if isinstance(a, np.ndarray) and a.ndim == 1:
+            # The batch's one 1-D input is its lane lengths: book the
+            # lanes with a message that each shard receives.
+            live = np.count_nonzero(a.reshape(len(self.shard_lanes), -1),
+                                    axis=1)
+            for i, n in enumerate(live.tolist()):
+                self.shard_lanes[i] += n
         return jax.device_put(a, self.in_sharding)
+
+    def note_weights(self, ns: int) -> None:
+        """One batch's rlc weights drawn and placed, in ns of host
+        time (the async verifier's on_weights)."""
+        self.rlc_weights_ns += ns
+        self.rlc_weights_calls += 1
 
     def note_dispatch(self, lanes: int) -> None:
         self.dispatches += 1
@@ -617,6 +638,7 @@ class EngineRegistry:
             e.msm_token = spec.resolved_msm()
 
         rlc_sharded = None
+        weights_sharding = None
         if spec.shards:
             if spec.batch % spec.shards:
                 raise ValueError(
@@ -631,8 +653,12 @@ class EngineRegistry:
             from jax.sharding import NamedSharding, PartitionSpec
 
             mesh = make_mesh(spec.shards)
-            e.in_sharding = NamedSharding(
-                mesh, PartitionSpec(mesh.axis_names[0]))
+            axis = mesh.axis_names[0]
+            e.in_sharding = NamedSharding(mesh, PartitionSpec(axis))
+            # The rlc weights go where put() sends the lanes: z by
+            # lane, u in its (K, 2, B) block layout by lane.
+            weights_sharding = (e.in_sharding, NamedSharding(
+                mesh, PartitionSpec(None, None, axis)))
             _sharded = verify_step_sharded(mesh)
 
             def direct_fn(msgs, lens, sigs, pubs):
@@ -656,11 +682,15 @@ class EngineRegistry:
                         mesh, plan=plan)
                     e.fn_local = local_fn
                     e.fn_tail = tail_fn
+                    span = flight.span_maker()
 
                     def rlc_sharded(msgs, lens, sigs, pubs, z, u):
-                        status, definite, parts = local_fn(
-                            msgs, lens, sigs, pubs, z, u)
-                        return status, definite, tail_fn(parts)
+                        with span("fd.pod.local_launch"):
+                            status, definite, parts = local_fn(
+                                msgs, lens, sigs, pubs, z, u)
+                        with span("fd.pod.tail_launch"):
+                            ok = tail_fn(parts)
+                        return status, definite, ok
                 else:
                     from firedancer_tpu.parallel.mesh import (
                         verify_rlc_step_sharded,
@@ -686,7 +716,9 @@ class EngineRegistry:
 
                 rlc_sharded = jax.jit(
                     functools.partial(verify_batch_rlc, plan=plan))
-            fn = make_async_verifier(direct_fn, rlc_fn=rlc_sharded)
+            fn = make_async_verifier(direct_fn, rlc_fn=rlc_sharded,
+                                     weights_sharding=weights_sharding,
+                                     on_weights=e.note_weights)
         e.direct_fn = direct_fn
         e.fn = fn
         # fd_drain: attach the dedup-filter aux graph (built like the

@@ -328,7 +328,7 @@ class FabricHost:
         """Rendezvous through the distributed-runtime KV store — NO
         collectives, so it works before the gloo clique exists. The
         compile-skew killer: two processes timesharing one core can
-        finish the big local_step compile minutes apart, and gloo's
+        finish the big pod_local compile minutes apart, and gloo's
         context rendezvous inside the FIRST collective execution times
         out at 30 s — every process must compile first, meet here,
         and only then execute."""

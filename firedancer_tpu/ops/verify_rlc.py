@@ -66,6 +66,8 @@ analysis that motivates the promotion.
 
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -527,7 +529,8 @@ class RlcAsyncResult:
 
 
 def make_async_verifier(fallback_fn, rng: np.random.Generator | None = None,
-                        rlc_fn=None, torsion_k: int | None = None):
+                        rlc_fn=None, torsion_k: int | None = None,
+                        weights_sharding=None, on_weights=None):
     """A drop-in for jit(verify_batch) with RLC fast-pass semantics.
 
     Returns fn(msgs, lens, sigs, pubs) -> RlcAsyncResult. fallback_fn is
@@ -538,6 +541,14 @@ def make_async_verifier(fallback_fn, rng: np.random.Generator | None = None,
     the soundness contract (module docstring) requires CSPRNG entropy
     in production; pass rng only for deterministic tests. torsion_k is
     the subgroup-check trial count (default FD_RLC_TORSION_K or 64).
+
+    weights_sharding (a mesh engine's (z_sharding, u_sharding)) places
+    the weights straight from the host onto the chips that use them:
+    z as (B, 32) by lane, u in the (K, 2, B) block layout
+    parallel/mesh shards, each chip receiving its own lanes' columns.
+    rlc_fn then takes u as (K, 2, B). Without it (one chip) both go to
+    the default device as (B, 32) and (K, 2B). on_weights(ns), where
+    given, is told the host time of each batch's draw and placement.
     """
     import jax
 
@@ -549,9 +560,19 @@ def make_async_verifier(fallback_fn, rng: np.random.Generator | None = None,
 
     def fn(msgs, lens, sigs, pubs):
         bsz = msgs.shape[0]
+        t0 = time.perf_counter_ns()
         with span("fd.rlc.weights"):
-            z = jnp.asarray(fresh_z(bsz, rng))
-            u = jnp.asarray(fresh_u(torsion_k, 2 * bsz, rng))
+            if weights_sharding is None:
+                z = jnp.asarray(fresh_z(bsz, rng))
+                u = jnp.asarray(fresh_u(torsion_k, 2 * bsz, rng))
+            else:
+                z_sh, u_sh = weights_sharding
+                z = jax.device_put(fresh_z(bsz, rng), z_sh)
+                u = jax.device_put(
+                    fresh_u(torsion_k, 2 * bsz, rng).reshape(
+                        torsion_k, 2, bsz), u_sh)
+        if on_weights is not None:
+            on_weights(time.perf_counter_ns() - t0)
         with span("fd.rlc.launch"):
             out = rlc(msgs, lens, sigs, pubs, z, u)
         return RlcAsyncResult(out, fallback_fn, (msgs, lens, sigs, pubs),

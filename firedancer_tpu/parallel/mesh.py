@@ -144,13 +144,20 @@ def verify_rlc_step_sharded(mesh: Mesh, plan=None):
     jitted = jax.jit(sharded)
 
     def fn(msgs, lens, sigs, pubs, z, u):
-        k = u.shape[0]
-        bsz = msgs.shape[0]
-        # (K, 2B) -> (K, 2, B): columns 0..B-1 are the A weights,
-        # B..2B-1 the R weights (verify_rlc.fresh_u's convention).
-        return jitted(msgs, lens, sigs, pubs, z, u.reshape(k, 2, bsz))
+        return jitted(msgs, lens, sigs, pubs, z, _u_blocks(u, msgs.shape[0]))
 
     return fn
+
+
+def _u_blocks(u, bsz: int):
+    """The torsion weights in the (K, 2, B) block layout the sharded
+    graphs take: a (K, 2B) u (columns 0..B-1 the A weights, B..2B-1 the
+    R weights, verify_rlc.fresh_u's convention) is reshaped; a u a mesh
+    engine already placed as (K, 2, B), sharded by lane, passes as it
+    is, so no chip receives another's columns."""
+    if u.ndim == 3:
+        return u
+    return u.reshape(u.shape[0], 2, bsz)
 
 
 def verify_rlc_split_sharded(mesh: Mesh, plan=None):
@@ -190,10 +197,8 @@ def verify_rlc_split_sharded(mesh: Mesh, plan=None):
     local_jit, combine_jit = _rlc_split_jits(mesh, plan)
 
     def local_fill(msgs, lens, sigs, pubs, z, u):
-        k = u.shape[0]
-        bsz = msgs.shape[0]
         return local_jit(msgs, lens, sigs, pubs, z,
-                         u.reshape(k, 2, bsz))
+                         _u_blocks(u, msgs.shape[0]))
 
     return local_fill, combine_jit
 
@@ -210,7 +215,9 @@ def _rlc_split_jits(mesh: Mesh, plan=None):
     if plan is None:
         plan = active_plan()
 
-    def local_step(msgs, lens, sigs, pubs, z, u3):
+    # The two programs are named for the chip's trace: its module line
+    # shows them as jit_pod_local and jit_pod_tail.
+    def pod_local(msgs, lens, sigs, pubs, z, u3):
         u = u3.reshape(u3.shape[0], -1)
         status, definite, parts = verify_rlc_local(
             msgs, lens, sigs, pubs, z, u, plan=plan)
@@ -219,7 +226,7 @@ def _rlc_split_jits(mesh: Mesh, plan=None):
         stacked = jax.tree_util.tree_map(lambda c: c[None], parts)
         return status, definite, stacked
 
-    def combine_step(parts):
+    def pod_tail(parts):
         # Each shard holds its own (1, ...) slice; drop the carrier
         # axis and let the combine's all_gather rebuild the (N, ...)
         # stack in mesh order — the collective lives HERE, not in
@@ -230,13 +237,13 @@ def _rlc_split_jits(mesh: Mesh, plan=None):
     spec = P(axis)
     parts_spec = _rlc_parts_spec(axis)
     local_sharded = shard_map_nocheck(
-        local_step,
+        pod_local,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, P(None, None, axis)),
         out_specs=(spec, spec, parts_spec),
     )
     combine_sharded = shard_map_nocheck(
-        combine_step,
+        pod_tail,
         mesh=mesh,
         in_specs=(parts_spec,),
         out_specs=P(),
@@ -265,7 +272,7 @@ def verify_rlc_split_global(mesh: Mesh, plan=None):
       combine_jit(parts) -> batch_ok
 
     Trace-identical to verify_rlc_split_sharded's graphs (same
-    local_step/combine_step bodies, same specs); only the host-side
+    pod_local/pod_tail bodies, same specs); only the host-side
     reshape convenience is dropped.
     """
     local_jit, combine_jit = _rlc_split_jits(mesh, plan)
