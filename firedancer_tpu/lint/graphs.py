@@ -355,10 +355,11 @@ def _prod(shape) -> int:
 def _pallas_record(eqn, mult: int) -> dict:
     """Leaf inventory of one pallas_call: name, residency estimate
     (block shapes when the grid mapping exposes them, full operands
-    otherwise), and fill identification — a kernel streaming >=3
-    identical (R, 32, L) int16 round buffers is a staged bucket fill
-    executing R*L madds (msm._STAGE_DTYPE is the only int16 in the
-    repo's graphs, so the signature is unambiguous)."""
+    otherwise), and fill identification — a kernel streaming an
+    (R, 96, L) int16 round buffer (msm._stage_niels: yp | ym | t2d
+    blocks) or >=3 identical (R, 32, L) int16 round buffers is a staged
+    bucket fill executing R*L madds (msm._STAGE_DTYPE is the only int16
+    in the repo's graphs, so the signature is unambiguous)."""
     avals_in = [v.aval for v in eqn.invars]
     avals_out = [v.aval for v in eqn.outvars]
     gm = eqn.params.get("grid_mapping")
@@ -371,10 +372,11 @@ def _pallas_record(eqn, mult: int) -> dict:
         vmem += _prod(dims) * a.dtype.itemsize
     name = str(eqn.params.get("name_and_src_info", "")).split(" ")[0]
     staged = [a for a in avals_in
-              if len(a.shape) == 3 and a.shape[1] == 32
+              if len(a.shape) == 3 and a.shape[1] in (32, 96)
               and str(a.dtype) == "int16"]
     fill = None
-    if len(staged) >= 3 and len({a.shape for a in staged}) == 1:
+    if staged and len({a.shape for a in staged}) == 1 and (
+            staged[0].shape[1] == 96 or len(staged) >= 3):
         r, _, lanes = staged[0].shape
         fill = (int(r), int(lanes), mult)
     return {"name": name, "vmem_bytes": int(vmem), "fill": fill,

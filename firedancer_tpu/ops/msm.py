@@ -652,16 +652,22 @@ _STAGE_DTYPE = jnp.int16
 
 def _stage_niels(points, idx, max_rounds: int, lanes: int, bsz: int,
                  niels=None, neg=None, lane_pad: int = 0):
-    """Gather per-round niels operands: (R, 32, L + lane_pad) x3,
-    identity-staged ((1, 1, 0) niels form) where a slot is empty.
+    """Gather per-round niels operands: (R, 96, L + lane_pad) int16,
+    each lane's column the three 32-limb blocks yp | ym | t2d, the
+    niels identity (1, 1, 0) where a slot is empty.
     points must have Z == 1 (decompress output / affine constants).
     niels, if given, is the precomputed (yp, ym, t2d) from the
     decompress kernel — skips three XLA field ops over the whole point
-    set. neg ((nw, nb, R) bool, signed plans) folds each negative
-    digit's point negation into the gather: -P in niels form is just
-    (ym, yp, -t2d), one elementwise select. lane_pad appends identity
-    columns so non-power-of-two signed grids meet the kernel's lane
-    alignment."""
+    set. neg ((nw, nb, R) bool, signed plans) selects each negative
+    digit's point negation, -P in niels form being (ym, yp, -t2d).
+    lane_pad appends identity columns so non-power-of-two signed grids
+    meet the kernel's lane alignment.
+
+    One row gather does all of it. The row table holds every point's
+    96 limbs, already int16, then the identity; signed plans stack the
+    negated points and a second identity below. Empty slots, signs and
+    pad lanes are folded into the row index, so the gathered rows need
+    no select and no convert — only one transpose into round layout."""
     if niels is not None:
         yp, ym, t2d = niels
     else:
@@ -670,32 +676,24 @@ def _stage_niels(points, idx, max_rounds: int, lanes: int, bsz: int,
         ym = fe.fe_sub(y, x)
         t2d = fe.fe_mul(t, fe.FE_D2)
 
-    sel = jnp.transpose(idx, (2, 0, 1)).reshape(max_rounds * lanes)
-    m = (sel >= 0)[None, :]
-    safe = jnp.clip(sel, 0, bsz - 1)
-    one0 = (jnp.arange(fe.NLIMBS, dtype=jnp.int32) == 0)[:, None]
+    limb = jnp.arange(3 * fe.NLIMBS, dtype=jnp.int32)
+    ident = ((limb % fe.NLIMBS == 0) & (limb < 2 * fe.NLIMBS))[None, :]
+    ident = ident.astype(_STAGE_DTYPE)                     # (1, 96)
 
-    gyp = jnp.where(m, yp[:, safe], one0.astype(jnp.int32))
-    gym = jnp.where(m, ym[:, safe], one0.astype(jnp.int32))
-    gtd = jnp.where(m, t2d[:, safe], 0)                    # (32, R*L)
+    def rows(*blocks):
+        return jnp.concatenate(blocks).T.astype(_STAGE_DTYPE)  # (B, 96)
+
+    table = [rows(yp, ym, t2d), ident]
+    sel = jnp.transpose(idx, (2, 0, 1)).reshape(max_rounds, lanes)
+    sel = jnp.where(sel >= 0, sel, bsz)         # row bsz: the identity
     if neg is not None:
-        ng = jnp.transpose(neg, (2, 0, 1)).reshape(
-            max_rounds * lanes
-        )[None, :]
-        gyp, gym = jnp.where(ng, gym, gyp), jnp.where(ng, gyp, gym)
-        gtd = jnp.where(ng, -gtd, gtd)
-
-    def stage(g, ident_one):
-        g = jnp.transpose(
-            g.reshape(fe.NLIMBS, max_rounds, lanes), (1, 0, 2)
-        ).astype(_STAGE_DTYPE)                             # (R, 32, L)
-        if lane_pad:
-            g = jnp.pad(g, ((0, 0), (0, 0), (0, lane_pad)))
-            if ident_one:
-                g = g.at[:, 0, lanes:].set(1)
-        return g
-
-    return stage(gyp, True), stage(gym, True), stage(gtd, False)
+        table += [rows(ym, yp, -t2d), ident]
+        ng = jnp.transpose(neg, (2, 0, 1)).reshape(max_rounds, lanes)
+        sel = sel + (bsz + 1) * ng.astype(jnp.int32)
+    if lane_pad:
+        sel = jnp.pad(sel, ((0, 0), (0, lane_pad)), constant_values=bsz)
+    staged = jnp.concatenate(table)[sel]                   # (R, L, 96)
+    return jnp.transpose(staged, (0, 2, 1))                # (R, 96, L)
 
 
 def msm_fast(scalars_bytes: jnp.ndarray, points, n_windows: int,
@@ -759,13 +757,10 @@ def msm_fast_partial(scalars_bytes: jnp.ndarray, points, n_windows: int,
 
     lanes = nw_grid * nb
     lane_pad = (-lanes) % 256 if nb != N_BUCKETS else 0
-    s_yp, s_ym, s_t2d = _stage_niels(points, idx, max_rounds, lanes, bsz,
-                                     niels=niels, neg=neg,
-                                     lane_pad=lane_pad)
+    staged = _stage_niels(points, idx, max_rounds, lanes, bsz,
+                          niels=niels, neg=neg, lane_pad=lane_pad)
 
-    bx, by, bz, bt = mp.fill_buckets_pallas(
-        s_yp, s_ym, s_t2d, interpret=interpret
-    )
+    bx, by, bz, bt = mp.fill_buckets_pallas(staged, interpret=interpret)
     if lane_pad:
         bx, by, bz, bt = (c[:, :lanes] for c in (bx, by, bz, bt))
 
@@ -879,11 +874,9 @@ def subgroup_fast_partial(points, u_digits: jnp.ndarray,
     idx, ok_fill = _staging_from_digits(d, bsz, max_rounds, n_buckets)
 
     lanes = k * n_buckets
-    s_yp, s_ym, s_t2d = _stage_niels(points, idx, max_rounds, lanes, bsz,
-                                     niels=niels)
-    bx, by, bz, bt = mp.fill_buckets_pallas(
-        s_yp, s_ym, s_t2d, interpret=interpret
-    )
+    staged = _stage_niels(points, idx, max_rounds, lanes, bsz,
+                          niels=niels)
+    bx, by, bz, bt = mp.fill_buckets_pallas(staged, interpret=interpret)
 
     k_pad = k + (-k) % 128                 # Mosaic lane-width alignment
 

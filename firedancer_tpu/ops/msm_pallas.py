@@ -76,18 +76,21 @@ def _identity4(lanes):
     return (zero, one, one, zero)
 
 
-def fill_buckets_pallas(yp, ym, t2d, lane_tile: int = 2048,
+def fill_buckets_pallas(rounds, lane_tile: int = 2048,
                         interpret: bool = False):
     """Accumulate staged niels rounds into bucket points.
 
-    yp/ym/t2d: (R, 32, L) int32 — round r's point for every
-    (window, bucket) lane, identity-staged where the slot is empty.
+    rounds: (R, 96, L) int16 — round r's point for every (window,
+    bucket) lane as three 32-limb blocks yp | ym | t2d, identity-staged
+    where the slot is empty (ops/msm._stage_niels). The three blocks are
+    read through their own BlockSpec index maps, so the kernel sees the
+    same three (1, 32, lane_tile) tiles a round it always did.
     Returns extended bucket points (x, y, z, t), each (32, L).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n_rounds, _, n_lanes = yp.shape
+    n_rounds, _, n_lanes = rounds.shape
     if n_lanes % lane_tile:
         # fd_msm2 plan grids (windows x buckets, buckets not a power of
         # two for signed-magnitude plans) are staged to a multiple of
@@ -128,13 +131,16 @@ def fill_buckets_pallas(yp, ym, t2d, lane_tile: int = 2048,
             oz[...] = z
             ot[...] = t
 
-    spec_in = pl.BlockSpec((1, NLIMBS, lane_tile), lambda i, r: (r, 0, i))
+    def spec_in(k):
+        return pl.BlockSpec((1, NLIMBS, lane_tile),
+                            lambda i, r: (r, k, i))
+
     spec_out = pl.BlockSpec((NLIMBS, lane_tile), lambda i, r: (0, i))
     out_shape = jax.ShapeDtypeStruct((NLIMBS, n_lanes), jnp.int32)
     return pl.pallas_call(
         kern,
         grid=(n_tiles, n_rounds),
-        in_specs=[spec_in] * 3,
+        in_specs=[spec_in(k) for k in range(3)],
         out_specs=[spec_out] * 4,
         out_shape=[out_shape] * 4,
         scratch_shapes=[
@@ -144,7 +150,7 @@ def fill_buckets_pallas(yp, ym, t2d, lane_tile: int = 2048,
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(yp, ym, t2d)
+    )(rounds, rounds, rounds)
 
 
 def mul_by_group_order_pallas(pt, d2_col, bits_col, interpret: bool = False):

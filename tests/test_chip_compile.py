@@ -135,3 +135,27 @@ def test_served_shapes_take_the_kernels(monkeypatch):
         "dsm": "pallas"}
     assert engine_kernels("rlc", B, shards=4)["frontend"] == "fused"
     assert engine_kernels("direct", B)["frontend"] == "fused"
+
+
+def test_torsion_niels_staging_is_one_row_gather(sds):
+    """The torsion grid's niels staging at the served shape (2B points,
+    K = 64 trials, 5-bit buckets) compiles to one gather, and to under
+    half the scratch of the three column gathers it replaced
+    (1,281,222,656 B)."""
+    import jax
+    import jax.numpy as jnp
+
+    from firedancer_tpu.ops import msm
+
+    bsz, k, nb = 2 * B, 64, 32
+    rounds = msm._default_rounds(bsz, nb)
+
+    def stage(idx, niels):
+        return msm._stage_niels(None, idx, rounds, k * nb, bsz,
+                                niels=niels)
+
+    compiled = jax.jit(stage).lower(
+        sds((k, nb, rounds), jnp.int32),
+        tuple(sds((32, bsz), jnp.int32) for _ in range(3))).compile()
+    assert compiled.as_text().count(" gather(") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1_281_222_656 // 2

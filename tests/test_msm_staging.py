@@ -114,3 +114,151 @@ def test_staging_table_routes_every_live_lane_once():
             lanes = idx[t, b][idx[t, b] >= 0]
             want = np.flatnonzero(d[t] == b) if b else np.array([], int)
             np.testing.assert_array_equal(lanes, want)
+
+
+# --------------------------------------------------------------------- #
+# Niels round staging: ops/msm._stage_niels.
+#
+# The staging gathers one int16 row per slot from a table of the points'
+# yp | ym | t2d limbs (negated forms and identities folded into the row
+# index). The oracle below is the column-gather formula it replaced:
+# three gathers of int32 columns, selects for empty slots and signs, a
+# convert to int16 and identity pad lanes. Both must stage the same
+# limbs bit for bit.
+# --------------------------------------------------------------------- #
+
+from firedancer_tpu.ops import fe25519 as fe  # noqa: E402
+
+
+def _stage_oracle(niels, idx, max_rounds, lanes, bsz, neg, lane_pad):
+    yp, ym, t2d = niels
+    sel = jnp.transpose(idx, (2, 0, 1)).reshape(max_rounds * lanes)
+    m = (sel >= 0)[None, :]
+    safe = jnp.clip(sel, 0, bsz - 1)
+    one0 = (jnp.arange(fe.NLIMBS, dtype=jnp.int32) == 0)[:, None]
+    gyp = jnp.where(m, yp[:, safe], one0.astype(jnp.int32))
+    gym = jnp.where(m, ym[:, safe], one0.astype(jnp.int32))
+    gtd = jnp.where(m, t2d[:, safe], 0)
+    if neg is not None:
+        ng = jnp.transpose(neg, (2, 0, 1)).reshape(
+            max_rounds * lanes)[None, :]
+        gyp, gym = jnp.where(ng, gym, gyp), jnp.where(ng, gyp, gym)
+        gtd = jnp.where(ng, -gtd, gtd)
+
+    def stage(g, ident_one):
+        g = jnp.transpose(
+            g.reshape(fe.NLIMBS, max_rounds, lanes), (1, 0, 2)
+        ).astype(jnp.int16)
+        if lane_pad:
+            g = jnp.pad(g, ((0, 0), (0, 0), (0, lane_pad)))
+            if ident_one:
+                g = g.at[:, 0, lanes:].set(1)
+        return g
+
+    return stage(gyp, True), stage(gym, True), stage(gtd, False)
+
+
+def _niels_limbs(rng, bsz):
+    # Staged limbs obey |limb| <= 1024 (the lazy-carry invariant).
+    return tuple(jnp.asarray(rng.integers(-1024, 1025, size=(32, bsz),
+                                          dtype=np.int32))
+                 for _ in range(3))
+
+
+def _affine_limbs(rng, bsz):
+    # (X, Y, Z = 1, T) with reduced 8-bit limbs; only X, Y, T are read.
+    x, y, t = (rng.integers(0, 256, size=(32, bsz), dtype=np.int32)
+               for _ in range(3))
+    one = np.zeros((32, bsz), np.int32)
+    one[0] = 1
+    return tuple(jnp.asarray(c) for c in (x, y, one, t))
+
+
+# (nw, B, n_buckets, signed, lane_pad, all_empty, niels given)
+STAGE_CASES = {
+    "z_grid_unsigned": (msm.WINDOWS_Z, 256, 128, False, 0, False, True),
+    "torsion_grid_5bit": (16, 512, 32, False, 0, False, True),
+    "signed_grid_neg_and_pad": (10, 300, 65, True, (-650) % 256, False,
+                                True),
+    "all_slots_empty": (4, 128, 32, True, 128, True, True),
+    "niels_from_points": (6, 200, 32, False, 0, False, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STAGE_CASES))
+def test_stage_niels_matches_column_gather_oracle(case):
+    nw, bsz, nb, signed, lane_pad, empty, given = STAGE_CASES[case]
+    rng = np.random.default_rng(2147483926)
+    rounds = msm._default_rounds(bsz, nb, signed=signed)
+    lanes = nw * nb
+    s = rng.integers(-(nb - 1) if signed else 0, nb, size=(nw, bsz),
+                     dtype=np.int32)
+    idx, _ = msm._staging_from_digits(jnp.asarray(np.abs(s)), bsz, rounds,
+                                      nb)
+    if empty:
+        idx = jnp.full_like(idx, -1)
+    neg = (msm._neg_table(jnp.asarray(s < 0), idx, bsz) if signed
+           else None)
+    if given:
+        niels, points = _niels_limbs(rng, bsz), None
+    else:
+        points, niels = _affine_limbs(rng, bsz), None
+        x, y, _, t = points
+        want_niels = (fe.fe_add(y, x), fe.fe_sub(y, x),
+                      fe.fe_mul(t, fe.FE_D2))
+    got = jax.jit(msm._stage_niels, static_argnums=(2, 3, 4, 7))(
+        points, idx, rounds, lanes, bsz, niels, neg, lane_pad)
+    want = _stage_oracle(niels if given else want_niels, idx, rounds,
+                         lanes, bsz, neg, lane_pad)
+    assert got.dtype == jnp.int16
+    assert got.shape == (rounds, 3 * fe.NLIMBS, lanes + lane_pad)
+    for k, w in enumerate(want):
+        np.testing.assert_array_equal(
+            np.asarray(got[:, k * fe.NLIMBS:(k + 1) * fe.NLIMBS]),
+            np.asarray(w))
+    if signed and not empty:
+        assert bool(jnp.any(neg))
+
+
+def _affine_lanes(pt, n):
+    """(X, Y, Z, ...) limb columns -> the first n lanes as affine ints."""
+    x, y, z = (fe.limbs_to_int(np.asarray(c))[:n] for c in pt[:3])
+    return [(int(a) * pow(int(c), fe.P - 2, fe.P) % fe.P,
+             int(b) * pow(int(c), fe.P - 2, fe.P) % fe.P)
+            for a, b, c in zip(x, y, z)]
+
+
+@pytest.mark.parametrize("which", ["msm", "subgroup"])
+def test_fast_partials_match_xla_partials(which):
+    """The staged rounds feed the fill kernel (interpret mode): the
+    kernel-path partials equal the XLA-path partials as group elements,
+    per window (msm) and per trial (subgroup)."""
+    from firedancer_tpu.ballet import ed25519 as oracle
+    from firedancer_tpu.msm_plan import BASELINE_PLAN
+    from firedancer_tpu.ops.verify_rlc import fresh_u
+
+    rng = np.random.default_rng(2147483927)
+    bsz = 6
+    pts = [oracle.scalarmult(int(k), oracle.B)
+           for k in rng.integers(1, 2**40, size=bsz)]
+    coords = np.zeros((4, 32, bsz), np.int32)
+    for i, (px, py) in enumerate(pts):
+        for j, v in enumerate((px, py, 1, px * py % fe.P)):
+            coords[j, :, i] = [(v >> (8 * k)) & 0xFF for k in range(32)]
+    points = tuple(jnp.asarray(c) for c in coords)
+    if which == "msm":
+        scal = np.zeros((bsz, 32), np.uint8)
+        scal[:, :2] = rng.integers(0, 256, size=(bsz, 2))
+        scal[:, 1] &= 0x3F                  # two exact 7-bit windows
+        got, ok = msm.msm_fast_partial(jnp.asarray(scal), points, 2,
+                                       interpret=True, plan=BASELINE_PLAN)
+        want, want_ok = msm.msm_partial(jnp.asarray(scal), points, 2,
+                                        plan=BASELINE_PLAN)
+        n = 2
+    else:
+        u = jnp.asarray(fresh_u(8, bsz, rng))
+        got, ok = msm.subgroup_fast_partial(points, u, interpret=True)
+        want, want_ok = msm.subgroup_partial(points, u, bucket_bits=5)
+        n = 8
+    assert bool(ok) and bool(want_ok)
+    assert _affine_lanes(got, n) == _affine_lanes(want, n)
